@@ -47,4 +47,6 @@ def main(matrix=None, argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main(argv=sys.argv[1:])

@@ -87,7 +87,6 @@ class ModelConfig:
     param_dtype: str = "bfloat16"
 
     # ---- execution flags (perf knobs; see EXPERIMENTS.md §Perf) -------------
-    use_pallas: bool = False         # True on real TPU; dry-run uses the XLA path
     decode_block_w: int = 256        # decode-attention KV block (serving engine
                                      # rounds cache capacity up to this so the
                                      # kernel never re-pads the cache per step)

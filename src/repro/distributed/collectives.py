@@ -15,7 +15,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -71,14 +70,14 @@ def make_seqpar_decode_attention(mesh: Mesh, *, batch_axes=("data",),
             return out.reshape(q_l.shape[0], 1, H, hd).astype(q_l.dtype)
 
         clen_spec = P() if jnp.asarray(cache_len).ndim == 0 else P(b)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(b, None, None, None),          # q [B,1→B,H,hd] flat
                       P(b, seq_axis, None, None),       # k cache
                       P(b, seq_axis, None, None),       # v cache
                       clen_spec),
             out_specs=P(b, None, None, None),
-            check_rep=False,
+            check_vma=False,
         )(q.reshape(q.shape[0], H, hd)[:, None], k_cache, v_cache, cache_len)
 
     return decode_attn

@@ -294,6 +294,48 @@ def constrain(x, *axes):
     return jax.lax.with_sharding_constraint(x, spec)
 
 
+def per_shard(fn, args, in_axes, out_axes):
+    """Call ``fn(*args)`` once per device on its own shard when the active
+    rule set spans several devices, and plainly otherwise.
+
+    XLA cannot partition a Pallas TPU kernel, so the model routes every
+    kernel call through here. ``in_axes`` gives each argument's logical
+    axes (one tuple per argument, ``()`` for a scalar); ``out_axes`` gives
+    the result's, or a tuple of such tuples for several results. A logical
+    axis whose mesh extent does not divide some argument's dim is
+    replicated in every argument and result, so the shards stay
+    consistent (q heads and KV heads name the same axis for that reason).
+    Each shard runs the kernel on whole batch rows and heads, so the
+    values are those of the single-device call.
+    """
+    rules = current_rules()
+    mesh = rules.get("mesh") if rules is not None else None
+    if mesh is None or mesh.devices.size == 1:
+        return fn(*args)
+
+    def extent(name):
+        phys = rules.get(name, ())
+        phys = (phys,) if isinstance(phys, str) else phys
+        n = 1
+        for m in phys:
+            n *= mesh.shape[m]
+        return n
+
+    dropped = {a for x, axes in zip(args, in_axes)
+               for dim, a in zip(jnp.shape(x), axes)
+               if a is not None and dim % extent(a)}
+
+    def spec(axes):
+        return _axes_to_spec([None if a in dropped else a for a in axes],
+                             rules)
+
+    many = bool(out_axes) and isinstance(out_axes[0], tuple)
+    out_specs = tuple(spec(a) for a in out_axes) if many else spec(out_axes)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=tuple(spec(a) for a in in_axes),
+                         out_specs=out_specs, check_vma=False)(*args)
+
+
 # ---------------------------------------------------------------------------
 # Committed placement (serving): device_put with divisibility fallback
 # ---------------------------------------------------------------------------
@@ -321,10 +363,17 @@ def _divisible_spec(shape, spec: P, mesh: Mesh) -> P:
     return P(*out)
 
 
+def put_shardings(tree, spec_tree, mesh: Mesh):
+    """Per-leaf ``NamedSharding``s for a pytree of arrays or shapes, from
+    per-leaf ``PartitionSpec``s (same structure), replicating dims that do
+    not divide their mesh axes."""
+    return jax.tree.map(
+        lambda x, spec: NamedSharding(mesh,
+                                      _divisible_spec(x.shape, spec, mesh)),
+        tree, spec_tree)
+
+
 def shard_put(tree, spec_tree, mesh: Mesh):
     """``device_put`` a pytree with per-leaf ``PartitionSpec``s (same
     structure), falling back to replication on non-divisible dims."""
-    def _put(x, spec):
-        return jax.device_put(
-            x, NamedSharding(mesh, _divisible_spec(x.shape, spec, mesh)))
-    return jax.tree.map(_put, tree, spec_tree)
+    return jax.device_put(tree, put_shardings(tree, spec_tree, mesh))

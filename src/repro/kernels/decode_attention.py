@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -70,7 +72,7 @@ def _kernel(clen_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def decode_attention(q, k_cache, v_cache, cache_len, *, q_per_kv: int,
                      window: Optional[int] = None, block_w: int = 256,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """q [B,1,H,hd]; caches [B,W,K,hd]; cache_len scalar or [B] int32."""
     B, W, K, hd = k_cache.shape
     H = q.shape[2]
@@ -110,6 +112,6 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, q_per_kv: int,
                           scale=1.0 / math.sqrt(hd), G=G),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(clen, qf, kp, vp)
     return out[:, None]

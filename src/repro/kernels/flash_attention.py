@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -62,7 +64,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, window: Optional[int] = None,
                     block_q: int = 128, block_kv: int = 128,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q,k,v: [B, S(T), H, hd] (KV already repeated to H heads). Causal."""
     B, S, H, hd = q.shape
     T = k.shape[1]
@@ -97,6 +99,6 @@ def flash_attention(q, k, v, *, window: Optional[int] = None,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qt, kt, vt)
     return jnp.moveaxis(out, 1, 2)[:, :S]

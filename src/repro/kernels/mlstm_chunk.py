@@ -8,11 +8,14 @@ the TPU-native replacement for the xLSTM CUDA step kernel (DESIGN.md §3).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -74,7 +77,8 @@ def _kernel(q_ref, k_ref, v_ref, ig_ref, fg_ref, h_ref,
     m_scr[0, 0] = m_next
 
 
-def mlstm_chunk(q, k, v, ig, fg, *, chunk: int = 128, interpret: bool = True):
+def mlstm_chunk(q, k, v, ig, fg, *, chunk: int = 128,
+                interpret: Optional[bool] = None):
     """Chunkwise mLSTM. q,k,v [B,S,H,hd]; ig,fg [B,S,H]. Returns h [B,S,H,hd]."""
     B, S, H, hd = q.shape
     L = min(chunk, S)
@@ -107,6 +111,6 @@ def mlstm_chunk(q, k, v, ig, fg, *, chunk: int = 128, interpret: bool = True):
             pltpu.VMEM((1, hd), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qt, kt, vt, igt, fgt)
     return jnp.moveaxis(h, 1, 2)[:, :S]

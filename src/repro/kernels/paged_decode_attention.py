@@ -16,11 +16,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -68,7 +71,7 @@ def _kernel(clen_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, cache_len, *,
-                           q_per_kv: int, interpret: bool = True):
+                           q_per_kv: int, interpret: Optional[bool] = None):
     """q [B,1,H,hd]; pools [P, page_size, K, hd]; block_tables [B, nw] int32;
     cache_len scalar or [B] int32 (the new token's K/V must already be
     written at position ``cache_len`` through the block table)."""
@@ -108,6 +111,6 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, cache_len, *,
                           scale=1.0 / math.sqrt(hd)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(clen, bt, qf, k_pool, v_pool)
     return out[:, None]

@@ -9,11 +9,14 @@ replacement for the GPU per-timestep kernel (DESIGN.md §3).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 
 def _kernel(a_ref, bx_ref, y_ref, h_scr, *, block_t: int, nt: int):
@@ -23,24 +26,18 @@ def _kernel(a_ref, bx_ref, y_ref, h_scr, *, block_t: int, nt: int):
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    a = a_ref[0].astype(jnp.float32)                  # [bT, bR]
-    bx = bx_ref[0].astype(jnp.float32)
-    h = h_scr[...]                                    # [1, bR]
+    def step(i, h):                                   # h [1, bR]
+        row = pl.ds(i, 1)
+        h = (a_ref[0, row, :].astype(jnp.float32) * h
+             + bx_ref[0, row, :].astype(jnp.float32))
+        y_ref[0, row, :] = h.astype(y_ref.dtype)
+        return h
 
-    def step(i, carry):
-        h, ys = carry
-        h = a[i][None, :] * h + bx[i][None, :]
-        ys = jax.lax.dynamic_update_slice(ys, h, (i, 0))
-        return h, ys
-
-    ys = jnp.zeros_like(a)
-    h, ys = jax.lax.fori_loop(0, block_t, step, (h, ys))
-    h_scr[...] = h
-    y_ref[0] = ys.astype(y_ref.dtype)
+    h_scr[...] = jax.lax.fori_loop(0, block_t, step, h_scr[...])
 
 
 def rglru_scan(a, bx, *, block_t: int = 128, block_r: int = 128,
-               interpret: bool = True):
+               interpret: Optional[bool] = None):
     """h_t = a_t ⊙ h_{t-1} + bx_t over [B, S, R]. Returns (y, h_last)."""
     B, S, R = a.shape
     block_t = min(block_t, S)
@@ -61,7 +58,7 @@ def rglru_scan(a, bx, *, block_t: int = 128, block_r: int = 128,
         out_specs=pl.BlockSpec((1, block_t, block_r), lambda b, ri, ti: (b, ti, ri)),
         out_shape=jax.ShapeDtypeStruct((B, Sp, Rp), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_r), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(ap, bp)
     y = y[:, :S, :R]
     return y, y[:, -1].astype(jnp.float32)

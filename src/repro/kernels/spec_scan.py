@@ -8,16 +8,19 @@ temp per element; this kernel fuses flag masking and the reduction into one
 VMEM pass so the (tiny but per-engine-step) scan never round-trips through
 HBM. One grid step — B × spec_len is far below a single VMEM tile.
 
-``interpret`` defaults to True in this CPU container (Pallas interpreter);
-pass interpret=False on real TPU.
+``interpret`` defaults to the platform: compiled on a TPU, the Pallas
+interpreter elsewhere.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 
 def _kernel(acc_ref, len_ref, m_ref, *, S: int):
@@ -30,7 +33,7 @@ def _kernel(acc_ref, len_ref, m_ref, *, S: int):
     m_ref[...] = jnp.minimum(first_bad, lens)
 
 
-def accept_len(accept, draft_lens, *, interpret: bool = True):
+def accept_len(accept, draft_lens, *, interpret: Optional[bool] = None):
     """accept [B, S] bool, draft_lens [B] int32 -> accepted prefix length [B].
 
     Column i of ``accept`` is the accept flag of draft token i; columns at or
@@ -42,7 +45,7 @@ def accept_len(accept, draft_lens, *, interpret: bool = True):
     m = pl.pallas_call(
         functools.partial(_kernel, S=S),
         out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(acc, lens)
     return m[:, 0]
 

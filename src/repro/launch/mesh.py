@@ -2,24 +2,38 @@
 
 A FUNCTION, not a module constant — importing this module never touches jax
 device state (jax locks the device count on first backend init).
+
+Every mesh here has Auto axes: the serving and training code place arrays
+with ``NamedSharding``s and in-jit constraints and let the partitioner
+propagate the rest (``jax.make_mesh`` defaults to Explicit axes, which make
+shardings part of array types).
 """
 from __future__ import annotations
 
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 (one v5e pod slice, 256 chips) or 2×16×16 (two pods, 512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
-def make_host_mesh():
-    """Degenerate 1×1 mesh over the real local device (CPU tests/examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+def _auto_mesh(shape, axes, devices=None):
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
+
+
+def make_host_mesh(device=None):
+    """Degenerate 1×1 mesh over one device: the first local device by
+    default, or ``device`` (a replica of a fleet on its own chip)."""
+    return _auto_mesh((1, 1), ("data", "model"),
+                      devices=None if device is None else [device])
 
 
 def make_test_mesh(shape=(2, 4), axis_names=None):
@@ -40,7 +54,7 @@ def make_test_mesh(shape=(2, 4), axis_names=None):
             f"mesh {tuple(shape)} needs {need} devices but the backend has "
             f"{have}; run under XLA_FLAGS=--xla_force_host_platform_"
             f"device_count={need} (set BEFORE jax initializes)")
-    return jax.make_mesh(tuple(shape), tuple(axis_names))
+    return _auto_mesh(shape, axis_names)
 
 
 # TPU v5e hardware constants used by the roofline analysis (per chip).

@@ -11,6 +11,16 @@ from repro.models import transformer as tfm
 from repro.models.layers import init_params, logical_axes, param_specs
 
 
+@functools.lru_cache(maxsize=None)
+def _init_program(cfg):
+    """Parameter init as one program: each leaf's float32 draw fuses into
+    its cast to the param dtype, so init holds no temporaries beside the
+    weights (op by op, the largest leaf's float32 draw and its random bits
+    sit beside them) and compiles once per configuration."""
+    defs = tfm.model_defs(cfg)
+    return jax.jit(lambda key: init_params(defs, key, cfg.param_dtype))
+
+
 class Model:
     """Stateless model handle for one ModelConfig."""
 
@@ -20,7 +30,7 @@ class Model:
 
     # ---- params ------------------------------------------------------------
     def init(self, key) -> Dict[str, Any]:
-        return init_params(self.defs, key, self.cfg.param_dtype)
+        return _init_program(self.cfg)(key)
 
     def param_specs(self):
         return param_specs(self.defs, self.cfg.param_dtype)

@@ -17,7 +17,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import constrain
+from repro import kernels
+from repro.distributed.sharding import constrain, per_shard
+from repro.kernels import rglru_scan as _krg
 from repro.models.layers import ParamDef, norm_defs
 
 C_RGLRU = 8.0
@@ -144,8 +146,7 @@ def apply_recurrent_mixer(p, x, cfg, *, cache=None, mode="full", length=None,
         yg = constrain(y * gate, "batch", None, "rnn_act")
         out = jnp.einsum("bsr,rd->bsd", yg, p["wo"])
         return out, {"hh": hh, "xs": xs, "h0": cache["h"]}
-    elif cfg.use_pallas:
-        from repro.kernels import rglru_scan as _krg
+    elif kernels.on_tpu():
         c, conv_state = causal_conv1d(u, p["conv_w"], p["conv_b"], prev_conv,
                                       length=length)
         a, bx = _gates(p, c)
@@ -154,7 +155,10 @@ def apply_recurrent_mixer(p, x, cfg, *, cache=None, mode="full", length=None,
             bx = jnp.where(mask[..., None], bx, 0.0)
         if h0 is not None:
             bx = bx.at[:, 0].add(a[:, 0] * h0.astype(jnp.float32))
-        y, h = _krg.rglru_scan(a.astype(c.dtype), bx.astype(c.dtype))
+        # the recurrence runs in float32, as the XLA scan does
+        axes = ("batch", None, "rnn")
+        y, h = per_shard(_krg.rglru_scan, (a, bx), (axes, axes),
+                         (axes, ("batch", "rnn")))
         y = y.astype(c.dtype)
     else:
         c, conv_state = causal_conv1d(u, p["conv_w"], p["conv_b"], prev_conv,

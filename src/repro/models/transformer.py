@@ -16,8 +16,12 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.configs import base as cfgbase
-from repro.distributed.sharding import constrain
+from repro.distributed.sharding import constrain, per_shard
+from repro.kernels import decode_attention as _kda
+from repro.kernels import flash_attention as _kfa
+from repro.kernels import paged_decode_attention as _kpda
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
@@ -117,12 +121,22 @@ def init_cache(cfg, batch: int, capacity: int):
 # ---------------------------------------------------------------------------
 
 
+# logical axes of a kernel's [B, S, H, hd] query / output: q heads share
+# the KV heads' axis so each shard keeps whole GQA groups
+_Q_AXES = ("batch", None, "kv_heads", None)
+
+
+def _len_axes(cache_len):
+    """Logical axes of a scalar or per-row [B] cache length."""
+    return ("batch",) if jnp.ndim(cache_len) else ()
+
+
 def _attn_mixer(p, x, cfg, *, kind, positions, mode, cache, cache_len,
                 decode_attn_fn, prefill_len=None, block_tables=None):
     """Attention temporal mixer (pre-norm residual handled by caller).
 
-    ``cfg.use_pallas`` routes the hot spots to the TPU kernels
-    (repro.kernels); the default XLA path is what the dry-run lowers.
+    On a TPU the hot spots run as compiled Pallas kernels (repro.kernels);
+    elsewhere the XLA references run.
     ``prefill_len`` (traced scalar) marks the valid prompt prefix when the
     input is right-padded to a prefill bucket — the cache write then keeps
     the last real positions, not the padded tail.
@@ -141,10 +155,15 @@ def _attn_mixer(p, x, cfg, *, kind, positions, mode, cache, cache_len,
         ps = cache["k"].shape[1]
         kc, vc = attn.paged_cache_update(cache["k"], cache["v"], k, v,
                                          block_tables, cache_len, ps)
-        if cfg.use_pallas:
-            from repro.kernels import paged_decode_attention as _kpda
-            o = _kpda.paged_decode_attention(q, kc, vc, block_tables,
-                                             cache_len, q_per_kv=cfg.q_per_kv)
+        if kernels.on_tpu():
+            # pages replicated: block tables index the whole pool
+            pool = (None, None, "kv_heads", None)
+            o = per_shard(
+                functools.partial(_kpda.paged_decode_attention,
+                                  q_per_kv=cfg.q_per_kv),
+                (q, kc, vc, block_tables, cache_len),
+                (_Q_AXES, pool, pool, ("batch", None),
+                 _len_axes(cache_len)), _Q_AXES)
         else:
             o = attn.paged_decode_attention_ref(q, kc, vc, block_tables,
                                                 cache_len,
@@ -152,11 +171,14 @@ def _attn_mixer(p, x, cfg, *, kind, positions, mode, cache, cache_len,
         new_cache = {"k": kc, "v": vc}
     elif mode == "decode":
         kc, vc = attn.cache_update(cache["k"], cache["v"], k, v, cache_len)
-        if cfg.use_pallas:
-            from repro.kernels import decode_attention as _kda
-            o = _kda.decode_attention(q, kc, vc, cache_len,
-                                      q_per_kv=cfg.q_per_kv, window=window,
-                                      block_w=cfg.decode_block_w)
+        if kernels.on_tpu():
+            rows = ("batch", None, "kv_heads", None)
+            o = per_shard(
+                functools.partial(_kda.decode_attention,
+                                  q_per_kv=cfg.q_per_kv, window=window,
+                                  block_w=cfg.decode_block_w),
+                (q, kc, vc, cache_len),
+                (_Q_AXES, rows, rows, _len_axes(cache_len)), _Q_AXES)
         else:
             o = decode_attn_fn(q, kc, vc, cache_len, q_per_kv=cfg.q_per_kv,
                                window=window)
@@ -251,9 +273,10 @@ def _attn_mixer(p, x, cfg, *, kind, positions, mode, cache, cache_len,
     else:
         kr = attn.repeat_kv(k, cfg.q_per_kv)
         vr = attn.repeat_kv(v, cfg.q_per_kv)
-        if cfg.use_pallas:
-            from repro.kernels import flash_attention as _kfa
-            o = _kfa.flash_attention(q, kr, vr, window=window)
+        if kernels.on_tpu():
+            o = per_shard(functools.partial(_kfa.flash_attention,
+                                            window=window),
+                          (q, kr, vr), (_Q_AXES,) * 3, _Q_AXES)
         else:
             o = attn.flash_attention(q, kr, vr, window=window,
                                      q_positions=positions[0])

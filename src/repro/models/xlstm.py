@@ -244,11 +244,9 @@ def apply_mlstm(p, x, cfg, *, cache=None, mode="full", length=None, mask=None):
         # takes the XLA chunkwise path)
         h, state = mlstm_chunkwise(q, k, v, ig, fg, cache["state"],
                                    chunk=cfg.mlstm_chunk)
-    elif cfg.use_pallas:
-        from repro.kernels import mlstm_chunk as _kmc
-        h = _kmc.mlstm_chunk(q, k, v, ig, fg, chunk=cfg.mlstm_chunk)
-        _, state = mlstm_chunkwise(q, k, v, ig, fg, chunk=cfg.mlstm_chunk)
     else:
+        # XLA on every platform: its chunk matmuls already run on the MXU,
+        # and it yields the state the cache needs alongside h
         h, state = mlstm_chunkwise(q, k, v, ig, fg, chunk=cfg.mlstm_chunk)
     h = _group_norm_heads(h, p["out_norm"])
     y = jnp.einsum("bsf,fd->bsd", h * jax.nn.silu(z), p["w_down"])

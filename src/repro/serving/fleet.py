@@ -49,9 +49,12 @@ completed turns survive. Elastic scale mirrors this: ``drain(i)``
 quiesces a replica, migrates its sessions, and closes it;
 ``add_replica()`` brings a new engine online sharing the fleet's weights.
 
-All replicas share one set of parameter arrays (reads only — nothing
-donates weights), so an N-replica fleet costs N× cache/activation memory
-but 1× weights, and greedy outputs are bit-identical across replicas.
+Replicas on one device share one set of parameter arrays (reads only —
+nothing donates weights), so such a fleet costs N× cache/activation memory
+but 1× weights. A replica given its own device (``meshes=[make_host_mesh(d)
+for d in jax.devices()]``) holds its own copy of the weights and its pools
+there, and computes there. Greedy outputs are bit-identical across replicas
+either way.
 """
 from __future__ import annotations
 
@@ -251,8 +254,9 @@ class FleetServer:
         self._migrated = 0
         self._replicas_failed = 0
         self._replicas_drained = 0
-        # replica 0 initializes the weights once; every peer shares the
-        # same arrays (reads only) — 1× weight memory, bit-identical greedy
+        # replica 0 initializes the weights once; every peer starts from the
+        # same arrays (reads only) — shared on one device, copied to its own
+        # device by a replica that has one — bit-identical greedy
         first = self._make_server(params, meshes[0] if meshes else None)
         self._params = first.params
         self._replicas.append(_Replica(0, first, self._pumped))

@@ -106,7 +106,7 @@ class EnginePrograms:
 
     def __init__(self, model, cfg, engine_cfg, *, capacity: int,
                  num_slots: int, eos_id: int, freeze_done_rows: bool,
-                 snapshots: bool, spec: bool, donate: bool,
+                 snapshots: bool, spec: bool,
                  injector=None, retry: RetryPolicy = None,
                  watchdog_s: float = None, rules=None):
         self.model = model
@@ -131,7 +131,10 @@ class EnginePrograms:
         self.watchdog_stalls = 0        # dispatches slower than watchdog_s
         self._retry_rng = random.Random(0)   # backoff jitter (deterministic)
 
-        dargs = (1,) if donate else ()
+        # every program donates the cache (or snapshot arena) it rewrites, so
+        # XLA updates it in place; the scheduler rebinds the result and never
+        # touches the donated buffers again
+        dargs = (1,)
         self._prefill_jit = jax.jit(self._prefill_fn, donate_argnums=dargs)
         self._decode_chunk_jit = jax.jit(self._decode_chunk_fn,
                                          donate_argnums=dargs)
@@ -141,11 +144,10 @@ class EnginePrograms:
                                          donate_argnums=dargs,
                                          static_argnames=("sample",))
         if snapshots:
-            d0 = (0,) if donate else ()
             self._snap_capture_jit = jax.jit(self._snap_capture_fn,
-                                             donate_argnums=d0)
+                                             donate_argnums=(0,))
             self._snap_restore_jit = jax.jit(self._snap_restore_fn,
-                                             donate_argnums=d0)
+                                             donate_argnums=(0,))
         if spec:
             # ONE jit per verify step for every arch: forward + accept +
             # accept-length state rewind (model.verify_commit) fused
@@ -369,7 +371,7 @@ class EnginePrograms:
         out_tok, out_len = accept_batched(
             logits, tokens, jnp.maximum(lens - 1, 0), key,
             temperature=temps, top_k=top_ks,
-            vocab_limit=self.cfg.vocab_size, use_kernel=self.cfg.use_pallas)
+            vocab_limit=self.cfg.vocab_size)
         cache = self.model.verify_commit(staged, clens, out_len, lens)
         return cache, out_tok, out_len
 

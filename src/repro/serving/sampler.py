@@ -14,6 +14,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
+from repro.distributed.sharding import per_shard
+from repro.kernels import spec_scan
+
 NEG = -1e30
 
 
@@ -89,7 +93,7 @@ def sample_batched(logits, key, *, temperature, top_k=None, vocab_limit: int = 0
 
 
 def accept_batched(logits, inputs, draft_lens, key, *, temperature,
-                   top_k=None, vocab_limit: int = 0, use_kernel: bool = False):
+                   top_k=None, vocab_limit: int = 0):
     """Batched draft acceptance for drafter-free speculative decoding.
 
     logits [B, S, V]:  verify-forward logits; ``logits[:, i]`` is the target
@@ -114,8 +118,8 @@ def accept_batched(logits, inputs, draft_lens, key, *, temperature,
     Returns (out_tokens [B, S], out_lens [B]): ``out_tokens[b, :m]`` are the
     accepted drafts, ``out_tokens[b, m]`` the correction (on reject) or bonus
     (full accept) token; ``out_lens = m + 1`` tokens are emitted per row.
-    ``use_kernel`` routes the accept-length reduction through the fused
-    Pallas scan (kernels/spec_scan.py) on TPU.
+    On a TPU the accept-length reduction runs as the fused Pallas scan
+    (kernels/spec_scan.py).
     """
     B, S, V = logits.shape
     logits = logits.astype(jnp.float32)
@@ -145,9 +149,9 @@ def accept_batched(logits, inputs, draft_lens, key, *, temperature,
         accept = jnp.where(temperature[:, None] > 0.0, u < p_draft,
                            greedy_tok == d_next)
 
-    from repro.kernels import spec_scan
-    if use_kernel:
-        m = spec_scan.accept_len(accept, draft_lens, interpret=False)
+    if kernels.on_tpu():
+        m = per_shard(spec_scan.accept_len, (accept, draft_lens),
+                      (("batch", None), ("batch",)), ("batch",))
     else:
         m = spec_scan.accept_len_ref(accept, draft_lens)
 

@@ -127,9 +127,6 @@ class EngineConfig:
                      (no per-step RNG / top-k sort).
     block_w:         decode-attention KV block; cache capacity is rounded up
                      to a multiple of it so the kernel never re-pads.
-    donate:          donate the shared cache to prefill/decode jits
-                     (None → auto: on everywhere except CPU, where XLA
-                     ignores donation and warns).
     cache_mode:      "dense" (PR-1 per-slot cache rows) or "paged" (radix
                      prefix sharing). On full-attention archs "paged" means
                      one KV page pool + per-request block tables
@@ -170,8 +167,10 @@ class EngineConfig:
     mesh:            JAX device mesh with ("data", "model") axes to shard the
                      serving programs over (``launch.mesh.make_test_mesh`` /
                      ``make_production_mesh``). None → ``make_host_mesh()``,
-                     a degenerate 1×1 mesh: every existing single-device
-                     path is byte-for-byte unchanged. With > 1 device the
+                     a degenerate 1×1 mesh over the first device. A 1×1
+                     mesh over another device (``make_host_mesh(device)``)
+                     puts params and pools on that device: one fleet
+                     replica per chip. With > 1 device the
                      scheduler lays params, the per-slot cache, the paged
                      page pool and the snapshot arena out with the bit-exact
                      "serve" rules (distributed/sharding.py): slot/page/row
@@ -183,7 +182,6 @@ class EngineConfig:
     prefill_buckets: Optional[Tuple[int, ...]] = None
     decode_chunk: int = 16
     block_w: int = 256
-    donate: Optional[bool] = None
     cache_mode: str = "dense"
     page_size: int = 16
     num_pages: Optional[int] = None
@@ -435,6 +433,14 @@ class Scheduler:
                                          if buckets is None else
                                          tuple(sorted(buckets)))
         key = jax.random.PRNGKey(seed)
+        if params is None and self.rules is not None:
+            # initialize straight into the mesh layout: a model that needs
+            # the whole mesh never has to fit on one device
+            placed = sharding.put_shardings(
+                self.model.param_specs(),
+                sharding.param_pspecs(self.model.param_axes(), self.rules),
+                mesh)
+            params = jax.jit(self.model.init, out_shardings=placed)(key)
         self.params = params if params is not None else self.model.init(key)
         if self.paged:
             self._bt_width = capacity // ps
@@ -497,6 +503,13 @@ class Scheduler:
             if self.snap_arena is not None:
                 self.snap_arena = kvpool.shard_rows(self.snap_arena, self.cfg,
                                                     self.rules, mesh)
+        else:
+            # one device: commit params and pools to the mesh's device, so a
+            # replica built on chip i computes on chip i (jit follows its
+            # committed arguments; arrays already there are not copied)
+            dev = mesh.devices.flat[0]
+            self.params, self.cache, self.snap_arena = jax.device_put(
+                (self.params, self.cache, self.snap_arena), dev)
         self.slots = [_Slot() for _ in range(num_slots)]
         self._queue: "collections.deque[Request]" = collections.deque()
         self._rng = jax.random.PRNGKey(seed + 1)   # spec verify/accept key
@@ -548,14 +561,11 @@ class Scheduler:
         self._svc_prefill_tok_s: Optional[float] = None
         self._svc_decode_tok_s: Optional[float] = None
 
-        donate = self.engine_cfg.donate
-        if donate is None:
-            donate = jax.default_backend() != "cpu"
         self.progs = EnginePrograms(
             self.model, self.cfg, self.engine_cfg, capacity=self.capacity,
             num_slots=num_slots, eos_id=self.tokenizer.eos_id,
             freeze_done_rows=self._freeze_done_rows, snapshots=self.snapshots,
-            spec=self.spec, donate=donate, injector=injector,
+            spec=self.spec, injector=injector,
             retry=self.retry, watchdog_s=watchdog_s, rules=self.rules)
         self._zero_key = jnp.zeros((2,), jnp.uint32)
         self._slot_consts = None        # cached (keys, prompt_lens) device

@@ -19,6 +19,7 @@ import threading
 import time
 
 import pytest
+from _host_devices import run_on_host_devices
 
 from repro.configs.registry import ARCHS
 from repro.serving.faults import OverloadError
@@ -363,3 +364,40 @@ def test_journal_restores_into_fresh_server_instance(arch, mode):
     assert bs.submit(t2, sp).result() == ref2, (arch, mode)
     a.close()
     b.close()
+
+
+_PER_DEVICE = r'''
+import jax
+from repro.configs.registry import ARCHS
+from repro.launch.mesh import make_host_mesh
+from repro.serving.fleet import FleetServer
+from repro.serving.server import EngineConfig, LLMServer, SamplingParams
+
+cfg = ARCHS["qwen2.5-3b"].reduced(vocab_size=512)
+devs = jax.devices()[:2]
+ecfg = EngineConfig(cache_mode="paged", page_size=8, spec_len=2)
+fleet = FleetServer(cfg, num_replicas=2, capacity=128, engine_cfg=ecfg,
+                    pump=False, meshes=[make_host_mesh(d) for d in devs])
+for d, r in zip(devs, fleet.replicas):
+    eng = r.server.engine
+    held = {x for a in jax.tree.leaves((eng.params, eng.cache))
+            for x in a.devices()}
+    assert held == {d}, (d, held)
+prompts = [f"request {i}: tell me about tool number {i}" for i in range(4)]
+sp = SamplingParams(max_new_tokens=10)
+hs = [fleet.submit(p, sp) for p in prompts]
+got = [h.result() for h in hs]
+assert all(n > 0 for n in (p["decode_tokens"]
+                           for p in fleet.stats()["per_replica"]))
+one = LLMServer(cfg, capacity=128, params=fleet.params, engine_cfg=ecfg)
+want = [h.result() for h in [one.submit(p, sp) for p in prompts]]
+assert got == want, (got, want)
+print("PER_DEVICE_OK")
+'''
+
+
+def test_replicas_on_their_own_devices_match_one_replica(tmp_path):
+    """A replica built on a one-device mesh holds its weights and pools on
+    that device (and computes there); greedy outputs equal one replica
+    serving the same requests. Runs in a child with two host devices."""
+    assert "PER_DEVICE_OK" in run_on_host_devices(tmp_path, _PER_DEVICE, 2)

@@ -174,14 +174,15 @@ def test_mlstm_chunk_invariant_to_chunk_size(S, chunk):
 
 
 # ---------------------------------------------------------------------------
-# use_pallas routing: the kernel path must equal the XLA path END TO END
+# kernel routing: on a TPU the model takes the kernels; the kernel path must
+# equal the XLA path END TO END (steered here onto the interpreted kernels)
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("name", ["qwen2.5-3b", "mixtral-8x22b",
                                   "recurrentgemma-9b", "xlstm-350m"])
-def test_use_pallas_model_parity(name):
-    import dataclasses
+def test_use_pallas_model_parity(name, monkeypatch):
+    from repro import kernels
     from repro.configs.registry import ARCHS
     from repro.models import Model
     from repro.models import transformer as tfm
@@ -191,6 +192,6 @@ def test_use_pallas_model_parity(name):
     tok = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab_size)
     batch = model.make_batch(tok)
     ref_logits, _, _ = tfm.forward_logits(params, batch, cfg, mode="train")
-    cfg_k = dataclasses.replace(cfg, use_pallas=True)
-    got_logits, _, _ = tfm.forward_logits(params, batch, cfg_k, mode="train")
+    monkeypatch.setattr(kernels, "on_tpu", lambda: True)
+    got_logits, _, _ = tfm.forward_logits(params, batch, cfg, mode="train")
     assert float(jnp.max(jnp.abs(got_logits - ref_logits))) < 3e-3

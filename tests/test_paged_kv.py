@@ -7,12 +7,14 @@ import pytest
 
 from repro.configs.registry import ARCHS
 from repro.kernels.paged_decode_attention import paged_decode_attention
+from repro.models import Model
 from repro.models import attention as attn
 from repro.serving.engine import EngineConfig, ServingEngine
 from repro.serving.kvpool import PagePool, block_table_array, supports_paged
 from repro.serving.radix import RadixTree
 
 from tests._hypothesis_compat import given, settings, st
+from tests._no_eos import without_eos
 
 PAGED_ARCHS = ["qwen2.5-3b", "chatglm3-6b", "granite-3-2b"]
 
@@ -249,7 +251,8 @@ def test_paged_mixed_batch_and_slot_reuse():
 
 def test_paged_pool_exhaustion_evicts_and_recovers():
     cfg = _cfg("qwen2.5-3b")
-    eng = ServingEngine(cfg, num_slots=2, capacity=64,
+    params = without_eos(Model(cfg).init(jax.random.PRNGKey(0)), cfg)
+    eng = ServingEngine(cfg, num_slots=2, capacity=64, params=params,
                         engine_cfg=EngineConfig(cache_mode="paged",
                                                 page_size=16, num_pages=9))
     reqs = [eng.submit(f"request number {i} with a shared tail of text",

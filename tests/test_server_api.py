@@ -12,6 +12,7 @@ from repro.serving.scheduler import SamplingParams, Scheduler
 from repro.serving.server import EngineConfig, LLMServer
 
 from tests._hypothesis_compat import given, settings, st
+from tests._no_eos import without_eos
 
 
 def _cfg(arch):
@@ -548,7 +549,8 @@ def test_stop_sequence_split_across_chunk_boundary(qwen, qwen_params):
     """A multi-token stop string whose pieces land in DIFFERENT decode
     chunks is still caught (the host-side check sees the whole decoded
     text), and tokens after the stop are trimmed from the result."""
-    srv = LLMServer(qwen, num_slots=1, capacity=96, params=qwen_params,
+    srv = LLMServer(qwen, num_slots=1, capacity=96,
+                    params=without_eos(qwen_params, qwen),
                     engine_cfg=EngineConfig(decode_chunk=4))
     free_h = srv.submit("tell me something", SamplingParams(max_new_tokens=16))
     free_text = free_h.result()

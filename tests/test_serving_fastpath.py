@@ -1,10 +1,14 @@
 """Serving fast path: bucketed prefill, chunked decode, on-device sampling."""
+import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.configs.registry import ARCHS
+from repro.models import Model
 from repro.serving.engine import EngineConfig, ServingEngine, _auto_buckets
 from repro.serving.sampler import sample_batched
+
+from tests._no_eos import without_eos
 
 
 @pytest.fixture(scope="module")
@@ -15,7 +19,8 @@ def cfg():
 
 @pytest.fixture(scope="module")
 def engine(cfg):
-    return ServingEngine(cfg, num_slots=3, capacity=96)
+    params = without_eos(Model(cfg).init(jax.random.PRNGKey(0)), cfg)
+    return ServingEngine(cfg, num_slots=3, capacity=96, params=params)
 
 
 # ---------------------------------------------------------------------------

@@ -4,6 +4,7 @@ what matter; divisibility is exercised by the 512-device dry-run)."""
 import jax
 import jax.numpy as jnp
 import pytest
+from _host_devices import run_on_host_devices
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import SHAPES
@@ -214,3 +215,52 @@ def test_seqpar_decode_attention_matches_ref(mesh):
             got = fn(q, kc, vc, clen, q_per_kv=G)
         want = ref.decode_attention(q, kc, vc, clen, q_per_kv=G)
         assert float(jnp.max(jnp.abs(got - want))) < 1e-5
+
+
+_PER_SHARD = r'''
+import functools
+import jax, jax.numpy as jnp
+from repro.distributed import sharding
+from repro.kernels.decode_attention import decode_attention
+from repro.kernels.paged_decode_attention import paged_decode_attention
+from repro.launch.mesh import make_test_mesh
+
+rules = sharding.rules_for(make_test_mesh((1, 4)), "serve")
+B, G, hd, ps, P, nw = 4, 2, 16, 8, 24, 4
+Q = ("batch", None, "kv_heads", None)
+for K in (4, 2):           # KV heads that divide the 4-way axis, and not
+    ks = jax.random.split(jax.random.PRNGKey(K), 4)
+    q = jax.random.normal(ks[0], (B, 1, K * G, hd))
+    kp = jax.random.normal(ks[1], (P, ps, K, hd))
+    vp = jax.random.normal(ks[2], (P, ps, K, hd))
+    bt = jax.random.randint(ks[3], (B, nw), 1, P)
+    n = jnp.asarray([3, 9, 17, 30], jnp.int32)
+    paged = functools.partial(paged_decode_attention, q_per_kv=G)
+    pool = (None, None, "kv_heads", None)
+
+    @jax.jit
+    def sharded(q, kp, vp, bt, n):
+        with sharding.use_rules(rules):
+            return sharding.per_shard(paged, (q, kp, vp, bt, n),
+                                      (Q, pool, pool, ("batch", None),
+                                       ("batch",)), Q)
+    assert bool(jnp.all(sharded(q, kp, vp, bt, n) == paged(q, kp, vp, bt, n)))
+    dense = functools.partial(decode_attention, q_per_kv=G, block_w=16)
+    kc = jax.random.normal(ks[1], (B, 32, K, hd))
+    rows = ("batch", None, "kv_heads", None)
+
+    @jax.jit
+    def sharded_dense(q, kc, n):
+        with sharding.use_rules(rules):
+            return sharding.per_shard(dense, (q, kc, kc, n),
+                                      (Q, rows, rows, ("batch",)), Q)
+    assert bool(jnp.all(sharded_dense(q, kc, n) == dense(q, kc, kc, n)))
+print("PER_SHARD_OK")
+'''
+
+
+def test_per_shard_kernels_match_the_plain_call(tmp_path):
+    """Kernel calls run per device under a multi-device serve rule set and
+    give the single-device values, whether or not the KV heads divide the
+    mesh axis. Runs in a child with four host devices."""
+    assert "PER_SHARD_OK" in run_on_host_devices(tmp_path, _PER_SHARD, 4)

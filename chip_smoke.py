@@ -11,12 +11,16 @@ cache and n-gram speculation (``spec_len=4``). Four sessions share a
 ~1k-token system prompt and take two greedy turns each, so bucketed
 prefill, paged extend over radix-shared pages, paged decode, speculative
 verify and session-tail reuse all run on the compiled Pallas kernels.
-Every handle must reach COMPLETED. The logits of the served paths (bf16,
-kernels) must agree with the float32 reference forward
-(``repro.models.reference``) over 256 positions of the prompt, for a
-bucketed prefill and for a paged extend followed by 32 paged decode steps:
-relative L2 error at most ``REL_L2_MAX`` and top-1 agreement at least
-``TOP1_MIN``.
+Every handle must reach COMPLETED. Two checks against the float32
+reference forward (``repro.models.reference``) on the same weights:
+  * the served greedy tokens of session 0, both turns, must equal the
+    reference's argmax given the same served context at ``TOP1_MIN`` of
+    their positions;
+  * at the model level (bf16, the kernels), the logits of a prefill
+    right-padded to its bucket and of a paged extend followed by 32 paged
+    decode steps must agree with the reference over 256 positions of the
+    prompt: relative L2 error at most ``REL_L2_MAX`` and top-1 agreement
+    at least ``TOP1_MIN``.
 
 Four chips (``--chips 4``) runs only what needs several chips, each beside
 what it is compared with:
@@ -57,6 +61,7 @@ REL_L2_MAX = 0.05
 # a few positions may flip on rounding, not a tenth of them
 TOP1_MIN = 0.90
 DECODE_CHECK = 32      # trailing positions decoded through the paged cache
+PREFILL_PAD = 23       # positions the prefill check leaves to bucket padding
 SYSTEM_TOKENS = 960    # shared system prompt length (tokens)
 NEW_TOKENS = 32        # greedy tokens per turn
 
@@ -136,7 +141,8 @@ def serve_sessions(server, system: str, n_sessions: int):
     """Three waves on one server: session 0's first turn alone (cold, it
     fills the radix trie), the other sessions' first turns (system prompt
     served from shared pages), then every session's second turn (served
-    from its retained tail). Returns (readings, outputs)."""
+    from its retained tail). Returns (readings, session 0's two turns'
+    requests)."""
     from repro.serving.scheduler import SamplingParams
     sp = SamplingParams(max_new_tokens=NEW_TOKENS)
     sessions = [server.open_session() for _ in range(n_sessions)]
@@ -144,8 +150,8 @@ def serve_sessions(server, system: str, n_sessions: int):
                "Assistant:" for i in range(n_sessions)]
     readings, outs = {}, [None] * n_sessions
     t0 = time.perf_counter()
-    o, readings["turn1_cold"] = _wave([sessions[0].submit(prompts[0], sp)],
-                                      t0)
+    first = sessions[0].submit(prompts[0], sp)
+    o, readings["turn1_cold"] = _wave([first], t0)
     outs[0] = o[0]
     t0 = time.perf_counter()
     o, readings["turn1_shared"] = _wave(
@@ -154,13 +160,46 @@ def serve_sessions(server, system: str, n_sessions: int):
     turn2 = [p + out + f"\nUser {i}: and after that?\nAssistant:"
              for i, (p, out) in enumerate(zip(prompts, outs))]
     t0 = time.perf_counter()
-    outs2, readings["turn2"] = _wave(
-        [s.submit(p, sp) for s, p in zip(sessions, turn2)], t0)
+    second = [s.submit(p, sp) for s, p in zip(sessions, turn2)]
+    _, readings["turn2"] = _wave(second, t0)
     expect(min(readings["turn1_shared"]["prefix_hit_tokens"]) > 0,
            "first turns did not share the system prompt's pages")
     expect(min(readings["turn2"]["prefix_hit_tokens"]) > 0,
            "second turns reused nothing")
-    return readings, outs + outs2
+    return readings, (first.request, second[0].request)
+
+
+def served_tokens_agreement(cfg, params, turns):
+    """Session 0's served greedy tokens, both turns, vs the float32
+    reference's argmax given the same served context (teacher forcing: one
+    bf16 near-tie flip would otherwise send a free-running greedy decode
+    elsewhere for every later token). The second turn's context holds the
+    first turn's prompt and output, so one reference forward over it,
+    right-padded (attention is causal), covers both turns. Returns
+    readings."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models.reference import reference_logits
+    r1, r2 = turns
+    n1, n2 = len(r1._ids), len(r2._ids)
+    seq = list(r2._ids) + list(r2.output_ids)
+    expect(seq[:n1] == list(r1._ids) and
+           seq[n1:n1 + len(r1.output_ids)] == list(r1.output_ids),
+           "second turn's context does not extend the first turn's")
+    length = -(-len(seq) // 256) * 256
+    tokens = jnp.asarray([seq + [0] * (length - len(seq))], jnp.int32)
+    top = jax.jit(lambda p, t: jnp.argmax(
+        reference_logits(p, t, cfg)[..., :cfg.vocab_size], -1))(params,
+                                                                tokens)
+    top = [int(t) for t in top[0]]
+    out = {}
+    for name, n, ids in (("turn1", n1, r1.output_ids),
+                         ("turn2", n2, r2.output_ids)):
+        want = top[n - 1:n - 1 + len(ids)]
+        out[name] = {"tokens": len(ids),
+                     "agree": sum(a == b for a, b in zip(ids, want))
+                     / len(ids)}
+    return out
 
 
 def _agreement(got, ref, vocab):
@@ -175,12 +214,14 @@ def _agreement(got, ref, vocab):
             "ref_std": float(jnp.std(ref))}
 
 
-def logits_agreement(cfg, params, tokens, n_decode=DECODE_CHECK):
-    """The served paths' logits (bf16, the platform's kernels) vs the
-    float32 reference forward on the same weights: bucketed prefill into a
-    dense cache (the flash kernel), and paged extend of all but the last
-    ``n_decode`` tokens followed by paged decode of those (the paged decode
-    kernel). Returns {path: readings}."""
+def logits_agreement(cfg, params, tokens, bucket, pad_id,
+                     n_decode=DECODE_CHECK, n_pad=PREFILL_PAD):
+    """The model's kernel paths (bf16, the platform's kernels) vs the
+    float32 reference forward on the same weights: prefill of the first
+    ``S - n_pad`` tokens right-padded to the prefill ``bucket`` with
+    ``length`` set, as the engine pads a prompt (the flash kernel), and
+    paged extend of all but the last ``n_decode`` tokens followed by paged
+    decode of those (the paged decode kernel). Returns {path: readings}."""
     import jax
     import jax.numpy as jnp
     from repro.models import Model
@@ -189,13 +230,18 @@ def logits_agreement(cfg, params, tokens, n_decode=DECODE_CHECK):
     model = Model(cfg)
     S = tokens.shape[1]
     S0 = S - n_decode
+    P = S - n_pad
+    expect(P <= bucket, f"prefill bucket {bucket} < {P} tokens")
     ps = 16
 
     @jax.jit
     def prefill(p, t):
-        logits, _ = model.prefill(p, model.make_batch(t),
-                                  model.init_cache(1, S))
-        return logits.astype(jnp.float32)
+        padded = jnp.full((1, bucket), pad_id, jnp.int32).at[:, :P].set(
+            t[:, :P])
+        logits, _ = model.prefill(p, model.make_batch(padded),
+                                  model.init_cache(1, bucket),
+                                  length=jnp.int32(P))
+        return logits[:, :P].astype(jnp.float32)
 
     @jax.jit
     def paged(p, t):
@@ -218,7 +264,7 @@ def logits_agreement(cfg, params, tokens, n_decode=DECODE_CHECK):
             jnp.float32)
 
     ref = jax.jit(lambda p, t: reference_logits(p, t, cfg))(params, tokens)
-    out = {"prefill": _agreement(prefill(params, tokens), ref,
+    out = {"prefill": _agreement(prefill(params, tokens), ref[:, :P],
                                  cfg.vocab_size)}
     got = paged(params, tokens)
     out["paged_extend"] = _agreement(got[:, :S0], ref[:, :S0],
@@ -252,7 +298,7 @@ def one_chip(cfg, seed: int, clock, *, num_slots=4, capacity=2048,
          compile_s_so_far=clock.seconds)
     system = system_prompt(server.tokenizer, seed, system_tokens)
     c0 = clock.seconds
-    readings, _ = serve_sessions(server, system, n_sessions)
+    readings, turns = serve_sessions(server, system, n_sessions)
     st = server.stats()
     expect(st["turn_prefix_hits"] == n_sessions,
            f"turn_prefix_hits {st['turn_prefix_hits']} != {n_sessions}")
@@ -268,11 +314,20 @@ def one_chip(cfg, seed: int, clock, *, num_slots=4, capacity=2048,
          decode_tokens=st["decode_tokens"], verify_steps=st["verify_steps"],
          acceptance_rate=st["acceptance_rate"],
          peak_bytes_in_use=peak_bytes(jax.devices()[:1]))
+    served = served_tokens_agreement(cfg, params, turns)
+    emit("served_tokens", top1_min=TOP1_MIN, **served)
+    for turn, a in served.items():
+        expect(a["agree"] >= TOP1_MIN,
+               f"{turn}: served tokens agree {a['agree']} < {TOP1_MIN}")
     ids = server.tokenizer.encode(system)[:check_positions]
-    agree = logits_agreement(cfg, params, jnp.asarray([ids], jnp.int32))
-    emit("logits", rel_l2_max=REL_L2_MAX, top1_min=TOP1_MIN, **agree)
+    bucket = min(b for b in server.engine.buckets
+                 if b >= check_positions - PREFILL_PAD)
+    agree = logits_agreement(cfg, params, jnp.asarray([ids], jnp.int32),
+                             bucket, server.tokenizer.pad_id)
+    emit("logits", rel_l2_max=REL_L2_MAX, top1_min=TOP1_MIN, bucket=bucket,
+         **agree)
     for path, a in agree.items():
-        expect(a["finite"], f"{path}: non-finite served logits")
+        expect(a["finite"], f"{path}: non-finite logits")
         expect(a["rel_l2"] <= REL_L2_MAX,
                f"{path}: rel_l2 {a['rel_l2']} > {REL_L2_MAX}")
         expect(a["top1_agree"] >= TOP1_MIN,
@@ -303,17 +358,13 @@ def fleet_phase(cfg, seed: int, devices, *, capacity=1024, n_requests=8):
     import jax
     from repro.launch.mesh import make_host_mesh
     from repro.serving.fleet import FleetServer
-    from repro.serving.pump import PumpConfig
     from repro.serving.scheduler import EngineConfig
     from repro.serving.server import LLMServer
     ecfg = EngineConfig(cache_mode="paged", spec_len=4)
     t0 = time.perf_counter()
-    # each replica's pump compiles its programs in its first steps, which
-    # takes minutes at full width: no heartbeat in that time is no stall
     fleet = FleetServer(cfg, num_replicas=len(devices), num_slots=4,
                         capacity=capacity, seed=seed, engine_cfg=ecfg,
-                        meshes=[make_host_mesh(d) for d in devices],
-                        pump=PumpConfig(stall_timeout_s=900.0))
+                        meshes=[make_host_mesh(d) for d in devices])
     try:
         for i, r in enumerate(fleet.replicas):
             eng = r.server.engine
@@ -331,6 +382,8 @@ def fleet_phase(cfg, seed: int, devices, *, capacity=1024, n_requests=8):
         stats = fleet.stats()
         per_replica = [p["decode_tokens"] if p else 0
                        for p in stats["per_replica"]]
+        compile_s = [p["pump_longest_compile_s"] if p else None
+                     for p in stats["per_replica"]]
         single = LLMServer(cfg, num_slots=4, capacity=capacity,
                            params=fleet.params, engine_cfg=ecfg)
         t0 = time.perf_counter()
@@ -342,6 +395,8 @@ def fleet_phase(cfg, seed: int, devices, *, capacity=1024, n_requests=8):
     emit("fleet", replicas=len(devices), setup_s=setup_s,
          fleet_wall_s=fleet_s, one_replica_wall_s=single_s,
          decode_tokens_per_replica=per_replica, identical=same,
+         pump_longest_compile_s=compile_s,
+         pump_stall_notices=stats["pump_stall_notices"],
          peak_bytes_in_use=peak_bytes(devices))
     expect(all(n > 0 for n in per_replica), "a replica served nothing")
     expect(all(same), "fleet output differs from one replica")

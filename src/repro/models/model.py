@@ -12,13 +12,17 @@ from repro.models.layers import init_params, logical_axes, param_specs
 
 
 @functools.lru_cache(maxsize=None)
-def _init_program(cfg):
+def _init_program(cfg, out_tree=None, out_leaves=()):
     """Parameter init as one program: each leaf's float32 draw fuses into
     its cast to the param dtype, so init holds no temporaries beside the
     weights (op by op, the largest leaf's float32 draw and its random bits
-    sit beside them) and compiles once per configuration."""
+    sit beside them) and compiles once per configuration and placement.
+    The placement comes flattened (``out_tree``, ``out_leaves``) so it can
+    key the cache."""
     defs = tfm.model_defs(cfg)
-    return jax.jit(lambda key: init_params(defs, key, cfg.param_dtype))
+    shardings = None if out_tree is None else out_tree.unflatten(out_leaves)
+    return jax.jit(lambda key: init_params(defs, key, cfg.param_dtype),
+                   out_shardings=shardings)
 
 
 class Model:
@@ -29,8 +33,14 @@ class Model:
         self.defs = tfm.model_defs(cfg)
 
     # ---- params ------------------------------------------------------------
-    def init(self, key) -> Dict[str, Any]:
-        return _init_program(self.cfg)(key)
+    def init(self, key, shardings=None) -> Dict[str, Any]:
+        """Random params from ``key``. ``shardings`` (one sharding, or a
+        pytree of them matching the params) places them as they are made,
+        so no device ever holds weights it does not keep."""
+        if shardings is None:
+            return _init_program(self.cfg)(key)
+        leaves, tree = jax.tree.flatten(shardings)
+        return _init_program(self.cfg, tree, tuple(leaves))(key)
 
     def param_specs(self):
         return param_specs(self.defs, self.cfg.param_dtype)

@@ -156,7 +156,10 @@ def _attn_mixer(p, x, cfg, *, kind, positions, mode, cache, cache_len,
         kc, vc = attn.paged_cache_update(cache["k"], cache["v"], k, v,
                                          block_tables, cache_len, ps)
         if kernels.on_tpu():
-            # pages replicated: block tables index the whole pool
+            # pages replicated: block tables index the whole pool. The
+            # serve layout puts pages over "data", so on a mesh with
+            # data > 1 every device all-gathers the pool here, per layer
+            # and step (sharding pages needs per-shard page ids)
             pool = (None, None, "kv_heads", None)
             o = per_shard(
                 functools.partial(_kpda.paged_decode_attention,

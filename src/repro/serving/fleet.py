@@ -313,6 +313,7 @@ class FleetServer:
             "dispatch_retries", "admission_retries", "watchdog_stalls",
             "journaled_sessions", "stream_chunks", "grouped_admissions",
             "engine_steps", "pump_steps", "pump_stall_notices",
+            "pump_compile_steps",
         ]
         with self._lock:
             out = {

@@ -20,7 +20,12 @@ thread-safe surfaces:
 Liveness watchdog: the pump heartbeats every loop iteration. A waiter whose
 wait outlives ``stall_timeout_s`` without a heartbeat — the pump is wedged
 inside a jit dispatch, or its thread died — raises a typed
-``PumpStalledError`` instead of hanging silently. A pump-loop crash
+``PumpStalledError`` instead of hanging silently. A step that compiles (a
+program's first call at new shapes takes minutes at full width on a cold
+cache) is progress, not a wedge: JAX records an event when it starts to
+trace or compile a program, the pump notes it when its own thread did so,
+and waiters then allow that step ``compile_timeout_s`` instead. A
+pump-loop crash
 (engine-level exception that escaped the scheduler's failure isolation)
 fails every outstanding request with the cause and wakes all waiters, so no
 handle is ever stranded.
@@ -36,26 +41,55 @@ import collections
 import dataclasses
 import threading
 import time
+import weakref
 from typing import Any, Callable, Optional
+
+import jax
 
 from repro.serving.faults import PumpStalledError
 
 __all__ = ["PumpConfig", "BackgroundPump"]
+
+# JAX records these as scalars when tracing / backend compilation of a jitted
+# program STARTS, on the thread that called it; a call that hits a compiled
+# program records neither.
+_COMPILE_START_EVENTS = frozenset({"/jax/core/compile/jaxpr_trace_duration",
+                                   "/jax/core/compile/backend_compile_duration"})
+_listener_lock = threading.Lock()
+_listener_installed = False
+
+
+def _on_jax_scalar(event, value, **_):
+    if event in _COMPILE_START_EVENTS:
+        ref = getattr(threading.current_thread(), "pump_ref", None)
+        pump = ref() if ref is not None else None
+        if pump is not None:
+            pump._compile_started()
+
+
+def _install_compile_listener():
+    global _listener_installed
+    with _listener_lock:
+        if not _listener_installed:
+            jax.monitoring.register_scalar_listener(_on_jax_scalar)
+            _listener_installed = True
 
 
 @dataclasses.dataclass(frozen=True)
 class PumpConfig:
     """Pump knobs.
 
-    stall_timeout_s: heartbeat staleness after which waiters raise
-                     ``PumpStalledError``. Must exceed the longest honest
-                     engine step (a cold jit compile easily takes seconds —
-                     keep this generous).
-    poll_s:          waiter re-check period; also the idle loop's nap, so it
-                     bounds how fast an idle pump notices new commands.
+    stall_timeout_s:   heartbeat staleness after which waiters raise
+                       ``PumpStalledError``. Must exceed the longest honest
+                       engine step that compiles nothing.
+    poll_s:            waiter re-check period; also the idle loop's nap, so
+                       it bounds how fast an idle pump notices new commands.
+    compile_timeout_s: how long a step that started compiling may run
+                       before waiters raise ``PumpStalledError``.
     """
     stall_timeout_s: float = 30.0
     poll_s: float = 0.05
+    compile_timeout_s: float = 600.0
 
 
 class BackgroundPump:
@@ -69,11 +103,16 @@ class BackgroundPump:
         self._stop = False
         self._crashed: Optional[BaseException] = None
         self._last_beat = time.monotonic()
+        self._compile_since: Optional[float] = None
         self._idle = threading.Event()
         self.steps = 0                  # pump loop iterations that stepped
         self.stall_notices = 0          # waiter-observed stalls (typed raises)
+        self.compile_steps = 0          # loop iterations that compiled
+        self.longest_compile_s = 0.0    # longest such iteration
         self.thread = threading.Thread(target=self._loop,
                                        name="llmserver-pump", daemon=True)
+        self.thread.pump_ref = weakref.ref(self)
+        _install_compile_listener()
 
     def start(self):
         self.thread.start()
@@ -166,6 +205,16 @@ class BackgroundPump:
         if not self.thread.is_alive():
             raise PumpStalledError(
                 f"pump thread died while waiting for {waiting_for}")
+        since = self._compile_since
+        if since is not None:
+            busy = time.monotonic() - since
+            if busy > self.cfg.compile_timeout_s:
+                self.stall_notices += 1
+                raise PumpStalledError(
+                    f"a compiling step has run {busy:.1f}s "
+                    f"(compile_timeout_s={self.cfg.compile_timeout_s}) "
+                    f"while waiting for {waiting_for}")
+            return
         stale = time.monotonic() - self._last_beat
         if stale > self.cfg.stall_timeout_s:
             self.stall_notices += 1
@@ -175,6 +224,23 @@ class BackgroundPump:
                 f"waiting for {waiting_for} — a dispatch is likely wedged")
 
     # ---- pump thread -------------------------------------------------------
+    def _compile_started(self):
+        """JAX began tracing or compiling on this pump's thread: the loop
+        iteration under way is compiling until its next heartbeat."""
+        if self._compile_since is None:
+            self._compile_since = time.monotonic()
+            self.compile_steps += 1
+
+    def _beat(self):
+        now = time.monotonic()
+        with self._cv:
+            if self._compile_since is not None:
+                self.longest_compile_s = max(self.longest_compile_s,
+                                             now - self._compile_since)
+                self._compile_since = None
+            self._last_beat = now
+            self._cv.notify_all()
+
     def _loop(self):
         try:
             while True:
@@ -190,6 +256,8 @@ class BackgroundPump:
                     except BaseException as e:
                         box["exc"] = e
                     done.set()
+                if cmds:
+                    self._beat()
                 if stop:
                     self._cancel_outstanding()
                     with self._cv:
@@ -197,9 +265,7 @@ class BackgroundPump:
                     return
                 outcome = self.server._step_impl()
                 self.steps += 1
-                with self._cv:
-                    self._last_beat = time.monotonic()
-                    self._cv.notify_all()
+                self._beat()
                 if outcome:             # PROGRESSED or WAITING (engine step
                     self._idle.clear()  # already back-pressured internally)
                     continue

@@ -433,15 +433,17 @@ class Scheduler:
                                          if buckets is None else
                                          tuple(sorted(buckets)))
         key = jax.random.PRNGKey(seed)
-        if params is None and self.rules is not None:
-            # initialize straight into the mesh layout: a model that needs
-            # the whole mesh never has to fit on one device
-            placed = sharding.put_shardings(
+        if params is None:
+            # initialize straight into the mesh layout (or onto the one
+            # device): a model that needs the whole mesh never has to fit
+            # on one device, and a replica on chip i never touches chip 0
+            placed = (sharding.put_shardings(
                 self.model.param_specs(),
                 sharding.param_pspecs(self.model.param_axes(), self.rules),
-                mesh)
-            params = jax.jit(self.model.init, out_shardings=placed)(key)
-        self.params = params if params is not None else self.model.init(key)
+                mesh) if self.rules is not None
+                else jax.sharding.SingleDeviceSharding(mesh.devices.flat[0]))
+            params = self.model.init(key, shardings=placed)
+        self.params = params
         if self.paged:
             self._bt_width = capacity // ps
             n_pages = self.engine_cfg.num_pages

@@ -358,6 +358,8 @@ class LLMServer:
                 "pump_alive": self._pump.alive,
                 "pump_steps": self._pump.steps,
                 "pump_stall_notices": self._pump.stall_notices,
+                "pump_compile_steps": self._pump.compile_steps,
+                "pump_longest_compile_s": self._pump.longest_compile_s,
             })
         return out
 

@@ -8,8 +8,8 @@ from _hypothesis_compat import given, settings, st
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.mlstm_chunk import mlstm_chunk
 from repro.kernels.rglru_scan import rglru_scan
+from repro.models.xlstm import mlstm_chunkwise
 
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
@@ -118,7 +118,7 @@ def test_rglru_kernel_matches_associative_scan_path():
 
 
 # ---------------------------------------------------------------------------
-# mLSTM chunkwise
+# mLSTM chunkwise: the model's XLA form on every platform (no Pallas kernel)
 # ---------------------------------------------------------------------------
 
 
@@ -133,11 +133,13 @@ def test_mlstm_chunk_sweep(B, S, H, hd, chunk, dtype):
     v = _rand(ks[2], (B, S, H, hd), dtype)
     ig = _rand(ks[3], (B, S, H), jnp.float32)
     fg = _rand(ks[4], (B, S, H), jnp.float32) + 2.0
-    got = mlstm_chunk(q, k, v, ig, fg, chunk=chunk)
-    want, _ = ref.mlstm(q.astype(jnp.float32), k.astype(jnp.float32),
-                        v.astype(jnp.float32), ig, fg)
+    got, state = mlstm_chunkwise(q, k, v, ig, fg, chunk=chunk)
+    want, want_state = ref.mlstm(q.astype(jnp.float32), k.astype(jnp.float32),
+                                 v.astype(jnp.float32), ig, fg)
     tol = 2e-5 if dtype == jnp.float32 else 5e-2
     assert jnp.max(jnp.abs(got.astype(jnp.float32) - want)) < tol
+    for a, b in zip(state, want_state):         # the carried (C, n, m)
+        assert jnp.max(jnp.abs(a - b)) < 2e-5
 
 
 # ---------------------------------------------------------------------------
@@ -158,21 +160,6 @@ def test_flash_attention_property(S, hd, window):
     assert jnp.max(jnp.abs(got - want)) < 3e-5
 
 
-@settings(deadline=None, max_examples=10)
-@given(S=st.integers(4, 64), chunk=st.sampled_from([4, 8, 16]))
-def test_mlstm_chunk_invariant_to_chunk_size(S, chunk):
-    """Chunk size is a tiling choice — results must not depend on it."""
-    ks = jax.random.split(jax.random.PRNGKey(S), 5)
-    q = _rand(ks[0], (1, S, 1, 16), jnp.float32)
-    k = _rand(ks[1], (1, S, 1, 16), jnp.float32)
-    v = _rand(ks[2], (1, S, 1, 16), jnp.float32)
-    ig = _rand(ks[3], (1, S, 1), jnp.float32)
-    fg = _rand(ks[4], (1, S, 1), jnp.float32) + 1.0
-    a = mlstm_chunk(q, k, v, ig, fg, chunk=chunk)
-    b = mlstm_chunk(q, k, v, ig, fg, chunk=S)
-    assert jnp.max(jnp.abs(a - b)) < 2e-5
-
-
 # ---------------------------------------------------------------------------
 # kernel routing: on a TPU the model takes the kernels; the kernel path must
 # equal the XLA path END TO END (steered here onto the interpreted kernels)
@@ -180,8 +167,8 @@ def test_mlstm_chunk_invariant_to_chunk_size(S, chunk):
 
 
 @pytest.mark.parametrize("name", ["qwen2.5-3b", "mixtral-8x22b",
-                                  "recurrentgemma-9b", "xlstm-350m"])
-def test_use_pallas_model_parity(name, monkeypatch):
+                                  "recurrentgemma-9b"])
+def test_kernel_path_model_parity(name, monkeypatch):
     from repro import kernels
     from repro.configs.registry import ARCHS
     from repro.models import Model

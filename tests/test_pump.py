@@ -7,7 +7,10 @@ lifecycle (close cancels, context manager, step() ownership), thread-safe
 submission from many threads, and the typed crash/stall surface.
 """
 import threading
+import time
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.configs.registry import ARCHS
@@ -238,5 +241,56 @@ def test_pump_stall_watchdog():
     srv.close()
     # the short stall_timeout_s also bounds close()'s join — give the
     # thread real time to leave its final engine step before teardown
+    pump.thread.join(30.0)
+    assert not pump.thread.is_alive()
+
+
+def _compiling_first_step(srv, hold_s):
+    """Make the pump's first step trace a fresh program (JAX then records
+    a compile start on the pump thread) and hold it ``hold_s``, as a cold
+    full-width compile would."""
+    real = srv._step_impl
+    first = threading.Event()
+
+    def step():
+        if not first.is_set():
+            first.set()
+            jax.jit(lambda x: x * 3 + 1)(jnp.arange(7.0))
+            time.sleep(hold_s)
+        return real()
+    srv._step_impl = step
+
+
+def test_pump_compiling_step_is_not_a_stall():
+    """A step that compiles may outlast stall_timeout_s: waiters hold it to
+    compile_timeout_s instead, and the step is counted. The server's own
+    programs compile on the pump thread too and are counted alike."""
+    with LLMServer(_cfg("qwen2.5-3b"), num_slots=1, capacity=64,
+                   pump=PumpConfig(stall_timeout_s=0.5, poll_s=0.02)) as srv:
+        _compiling_first_step(srv, 2.0)
+        h = srv.submit("hello", SamplingParams(max_new_tokens=4))
+        h.result()
+        assert h.status().value == "completed"
+        st = srv.stats()
+        assert st["pump_stall_notices"] == 0
+        assert st["pump_compile_steps"] >= 1
+        assert st["pump_longest_compile_s"] >= 2.0
+        # a prompt in a longer prefill bucket compiles a new program
+        srv.submit("a prompt long enough for another prefill bucket",
+                   SamplingParams(max_new_tokens=4)).result()
+        assert srv.stats()["pump_compile_steps"] > st["pump_compile_steps"]
+
+
+def test_pump_compile_timeout():
+    """A compiling step that outlasts compile_timeout_s is a typed stall."""
+    srv = LLMServer(_cfg("qwen2.5-3b"), num_slots=1, capacity=64,
+                    pump=PumpConfig(stall_timeout_s=0.5, poll_s=0.02,
+                                    compile_timeout_s=0.3))
+    _compiling_first_step(srv, 3.0)
+    h = srv.submit("hello", SamplingParams(max_new_tokens=4))
+    with pytest.raises(PumpStalledError, match="compiling step"):
+        h.result()
+    pump = srv._pump
+    srv.close()
     pump.thread.join(30.0)
     assert not pump.thread.is_alive()

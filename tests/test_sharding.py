@@ -264,3 +264,59 @@ def test_per_shard_kernels_match_the_plain_call(tmp_path):
     give the single-device values, whether or not the KV heads divide the
     mesh axis. Runs in a child with four host devices."""
     assert "PER_SHARD_OK" in run_on_host_devices(tmp_path, _PER_SHARD, 4)
+
+
+_PER_SHARD_DATA = r'''
+import functools
+import jax, jax.numpy as jnp
+from repro.configs.registry import ARCHS
+from repro.distributed import sharding
+from repro.kernels.paged_decode_attention import paged_decode_attention
+from repro.launch.mesh import make_test_mesh
+from repro.serving import kvpool
+
+mesh = make_test_mesh((2, 4))
+rules = sharding.rules_for(mesh, "serve")
+cfg = ARCHS["qwen2.5-3b"].reduced(num_heads=8, num_kv_heads=4, head_dim=16)
+B, G, ps, P, nw = 4, 2, 8, 24, 4
+K, hd = cfg.num_kv_heads, cfg.head_dim
+# the serving layout: pages over "data", KV heads over "model"
+pool = kvpool.shard_rows(kvpool.init_paged_cache(cfg, P, ps), cfg, rules,
+                         mesh)
+kl, vl = jax.tree.leaves(pool)[:2]       # one layer group's [L, P, ps, K, hd]
+ks = jax.random.split(jax.random.PRNGKey(0), 4)
+kp = jax.device_put(jax.random.normal(ks[1], kl.shape, kl.dtype), kl.sharding)
+vp = jax.device_put(jax.random.normal(ks[2], vl.shape, vl.dtype), vl.sharding)
+assert kp.sharding.spec[1] == "data"
+q = jax.random.normal(ks[0], (B, 1, K * G, hd), kp.dtype)
+bt = jax.random.randint(ks[3], (B, nw), 1, P)
+n = jnp.asarray([3, 9, 17, 30], jnp.int32)
+paged = functools.partial(paged_decode_attention, q_per_kv=G)
+Q = ("batch", None, "kv_heads", None)
+pages = (None, None, "kv_heads", None)
+
+
+@jax.jit
+def sharded(q, kp, vp, bt, n):
+    with sharding.use_rules(rules):
+        return sharding.per_shard(paged, (q, kp[0], vp[0], bt, n),
+                                  (Q, pages, pages, ("batch", None),
+                                   ("batch",)), Q)
+
+
+assert bool(jnp.all(sharded(q, kp, vp, bt, n) == paged(q, kp[0], vp[0], bt, n)))
+hlo = sharded.lower(q, kp, vp, bt, n).compile().as_text()
+assert "all-gather" in hlo
+print("PER_SHARD_GATHERS_PAGES")
+'''
+
+
+def test_per_shard_paged_kernel_gathers_pages_over_data(tmp_path):
+    """On a mesh with data > 1 the pool's pages are laid out over "data",
+    but the paged decode kernel indexes the whole pool through block
+    tables, so each device all-gathers the pages it does not hold before
+    the kernel runs: the values still equal the plain call, at the cost of
+    a whole-pool gather per layer and step. Runs in a child with eight host
+    devices."""
+    out = run_on_host_devices(tmp_path, _PER_SHARD_DATA, 8)
+    assert "PER_SHARD_GATHERS_PAGES" in out

@@ -21,18 +21,6 @@ def main() -> None:
     d7a = fig7a_caching.main()
     fig7b_consolidation.main()
 
-    try:
-        from benchmarks import roofline
-        rows = roofline.analyze()
-        print("roofline,arch,shape,compute_s,memory_s,collective_s,dominant,"
-              "useful_ratio,mfu_bound_pct")
-        for r in rows:
-            print(f"roofline,{r['arch']},{r['shape']},{r['compute_s']:.4f},"
-                  f"{r['memory_s']:.4f},{r['collective_s']:.4f},{r['dominant']},"
-                  f"{r['useful_ratio']:.3f},{r['mfu_bound'] * 100:.2f}")
-    except FileNotFoundError:
-        print("roofline,skipped (run repro.launch.dryrun --all first)")
-
     # ---- validation vs the paper's claims --------------------------------
     print("\n=== validation vs paper claims ===")
     checks = [

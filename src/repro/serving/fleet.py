@@ -66,8 +66,8 @@ from typing import Dict, List, Optional, Union
 from repro.serving.faults import OverloadError, PumpStalledError
 from repro.serving.journal import SessionJournal
 from repro.serving.pump import PumpConfig
-from repro.serving.scheduler import (EngineConfig, OverloadPolicy,
-                                     SamplingParams)
+from repro.serving.scheduler import (EngineConfig, LogHistogram,
+                                     OverloadPolicy, SamplingParams)
 from repro.serving.server import Handle, LLMServer, Session, StepOutcome
 from repro.serving.tokenizer import ByteTokenizer
 
@@ -313,7 +313,7 @@ class FleetServer:
             "dispatch_retries", "admission_retries", "watchdog_stalls",
             "journaled_sessions", "stream_chunks", "grouped_admissions",
             "engine_steps", "pump_steps", "pump_stall_notices",
-            "pump_compile_steps",
+            "pump_compile_steps", "host_gap_s", "host_gaps",
         ]
         with self._lock:
             out = {
@@ -336,6 +336,13 @@ class FleetServer:
             (p.get("ewma_decode_s_per_tok", 0.0) for p in live), default=0.0)
         out["prefix_hit_rate"] = (out["prefix_hit_tokens"]
                                   / max(out["prompt_tokens"], 1))
+        # request-stage histograms add bucket by bucket
+        for k in ("queue_wait_hist", "admit_hist", "cmd_wait_hist"):
+            hists = [p[k] for p in live if k in p]
+            if hists:
+                out[k] = LogHistogram.merge(hists)
+        if live:
+            out["hist_bounds_s"] = live[0]["hist_bounds_s"]
         out["per_replica"] = per
         return out
 

@@ -13,6 +13,9 @@ thread-safe surfaces:
   thread that ever touches the engine. Every command pending at the top of
   a loop iteration executes before the next ``step()`` — a burst of submits
   from N workflow threads lands in one admission round and co-batches.
+  Each command's wait from its enqueue to the start of its thunk is
+  counted in ``cmd_wait`` (``stats()["cmd_wait_hist"]``), and the loop's
+  phases carry ``serve.*`` profiler spans (docs/serving.md).
 * **the progress condition** — handle streams (``Handle.stream()`` /
   ``result()``) and ``wait_idle()`` block on it; the pump notifies after
   every engine step, right after delivering freshly decoded text.
@@ -45,8 +48,10 @@ import weakref
 from typing import Any, Callable, Optional
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.serving.faults import PumpStalledError
+from repro.serving.scheduler import LogHistogram
 
 __all__ = ["PumpConfig", "BackgroundPump"]
 
@@ -109,6 +114,7 @@ class BackgroundPump:
         self.stall_notices = 0          # waiter-observed stalls (typed raises)
         self.compile_steps = 0          # loop iterations that compiled
         self.longest_compile_s = 0.0    # longest such iteration
+        self.cmd_wait = LogHistogram()  # command enqueue -> its thunk starts
         self.thread = threading.Thread(target=self._loop,
                                        name="llmserver-pump", daemon=True)
         self.thread.pump_ref = weakref.ref(self)
@@ -135,7 +141,7 @@ class BackgroundPump:
                 raise PumpStalledError(
                     "pump is closed" if self._stop else
                     f"pump is dead: {self._crashed!r}")
-            self._commands.append((fn, box, done))
+            self._commands.append((fn, box, done, time.perf_counter()))
             self._cv.notify_all()
         while not done.wait(self.cfg.poll_s):
             self._check_live("a queued command")
@@ -250,13 +256,14 @@ class BackgroundPump:
                     stop = self._stop
                 if cmds:
                     self._idle.clear()
-                for fn, box, done in cmds:
-                    try:
-                        box["result"] = fn()
-                    except BaseException as e:
-                        box["exc"] = e
-                    done.set()
-                if cmds:
+                    with TraceAnnotation("serve.commands"):
+                        for fn, box, done, queued in cmds:
+                            self.cmd_wait.add(time.perf_counter() - queued)
+                            try:
+                                box["result"] = fn()
+                            except BaseException as e:
+                                box["exc"] = e
+                            done.set()
                     self._beat()
                 if stop:
                     self._cancel_outstanding()

@@ -48,6 +48,7 @@ without session reuse (tests/test_server_api.py).
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import dataclasses
 import time
@@ -58,6 +59,7 @@ import random
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.distributed import sharding
 from repro.launch.mesh import make_host_mesh
@@ -242,6 +244,32 @@ class OverloadPolicy:
     breaker_cooldown_s: float = 1.0
 
 
+class LogHistogram:
+    """Cumulative counts of durations (seconds) in log-spaced buckets.
+
+    ``BOUNDS_S`` are the upper bucket edges, 0.1 ms to 1,000 s at a ratio
+    of 10 ** (1/25) (about 1.096). ``counts()[i]`` holds the observations
+    in ``(BOUNDS_S[i - 1], BOUNDS_S[i]]``; the first bucket also holds
+    everything below 0.1 ms and the last one (index ``len(BOUNDS_S)``)
+    everything above 1,000 s. Counts only grow, so a reader takes window
+    deltas; replicas' counts add bucket by bucket (``merge``)."""
+
+    BOUNDS_S = tuple(1e-4 * 10 ** (i / 25) for i in range(7 * 25 + 1))
+
+    def __init__(self):
+        self._counts = [0] * (len(self.BOUNDS_S) + 1)
+
+    def add(self, seconds: float):
+        self._counts[bisect.bisect_left(self.BOUNDS_S, seconds)] += 1
+
+    def counts(self) -> List[int]:
+        return list(self._counts)
+
+    @staticmethod
+    def merge(counts: List[List[int]]) -> List[int]:
+        return [sum(col) for col in zip(*counts)]
+
+
 @dataclasses.dataclass(eq=False)
 class Request:
     rid: int
@@ -275,6 +303,8 @@ class Request:
     error: Optional[BaseException] = None    # why FAILED / TIMED_OUT
     deadline_s: Optional[float] = None       # resolved (param or server default)
     _submit_t: float = 0.0
+    admit_t: float = 0.0           # start of the admission attempt that
+                                   # sampled the first token (perf_counter)
     _retry_at: float = 0.0         # admission backoff: skip until this time
     _admit_attempts: int = 0       # failed admission tries (pool exhaustion)
     _ids: Optional[list] = None    # tokenized prompt, cached across admission
@@ -562,6 +592,19 @@ class Scheduler:
         # None until the first completion is observed
         self._svc_prefill_tok_s: Optional[float] = None
         self._svc_decode_tok_s: Optional[float] = None
+        # request stages, once per request at the admission that samples
+        # its first token: enqueue -> admission start, admission start ->
+        # first token
+        self._queue_wait = LogHistogram()
+        self._admit_time = LogHistogram()
+        # host gap: one decode chunk's sync -> the next chunk's dispatch,
+        # less the first-token and verify syncs in between (the device
+        # waits on the loop); counted only for chunks of consecutive
+        # engine steps
+        self._host_gap_s = 0.0
+        self._host_gaps = 0
+        self._chunk_synced_t: Optional[float] = None
+        self._sync_wait_s = 0.0             # those syncs since then
 
         self.progs = EnginePrograms(
             self.model, self.cfg, self.engine_cfg, capacity=self.capacity,
@@ -1095,6 +1138,14 @@ class Scheduler:
             "engine_steps": self._steps,
             "active_slots_per_step": self._active_slot_sum /
                 max(self._steps, 1),
+            # request stages as cumulative LogHistogram bucket counts (upper
+            # edges in hist_bounds_s), and the loop's host gap between
+            # consecutive decode chunks
+            "hist_bounds_s": list(LogHistogram.BOUNDS_S),
+            "queue_wait_hist": self._queue_wait.counts(),
+            "admit_hist": self._admit_time.counts(),
+            "host_gap_s": self._host_gap_s,
+            "host_gaps": self._host_gaps,
         }
         if self.paged or self.snapshots:
             out.update({
@@ -1252,14 +1303,21 @@ class Scheduler:
         slot.cache_len = len(ids)
         slot.prompt_len = len(ids) - len(pre)
         slot.remaining = req.max_new_tokens - len(pre) - 1
-        slot.generated = list(pre) + [int(first)]         # one host sync
+        t = time.perf_counter()
+        with TraceAnnotation("serve.first_token_wait"):
+            first = int(first)                            # one host sync
+        now = time.perf_counter()
+        self._sync_wait_s += now - t
+        slot.generated = list(pre) + [first]
         if pre:
             self._preempt_resumes += 1
         else:
             req._orig_plen = len(ids)
         req._pre_gen = None
         if req.first_token_s == 0.0:
-            req.first_token_s = time.perf_counter() - req._submit_t
+            req.first_token_s = now - req._submit_t
+            self._queue_wait.add(req.admit_t - req._submit_t)
+            self._admit_time.add(now - req.admit_t)
         req.status = "running"
         self._arm_spec(slot, ids)
         self._slot_consts = None        # slot membership changed
@@ -1555,8 +1613,11 @@ class Scheduler:
                 if req is None:
                     break
                 t0 = time.perf_counter()
+                if req.first_token_s == 0.0:
+                    req.admit_t = t0
                 try:
-                    admitted = admit(si, slot, req)
+                    with TraceAnnotation("serve.admit"):
+                        admitted = admit(si, slot, req)
                 except (RequestFault, DeadLetterError) as e:
                     # failure isolation: only this request dies; the slot
                     # is still free for the next candidate
@@ -1794,15 +1855,20 @@ class Scheduler:
         self.cache, out_tok, out_len = self.progs.verify(
             self.params, self.cache, tokens, clens, lens_a, temps, top_ks,
             k, bt)
-        # the ONE host sync of the verify step
-        out_tok, out_len = jax.device_get((out_tok, out_len))
+        # the ONE host sync of the verify step (device time, not a host gap)
+        t = time.perf_counter()
+        with TraceAnnotation("serve.decode_wait"):
+            out_tok, out_len = jax.device_get((out_tok, out_len))
+        now = time.perf_counter()
+        self._sync_wait_s += now - t
         self._breaker_note(True)
         self._decode_syncs += 1
         self._verify_steps += 1
-        dt = time.perf_counter() - t0
-        for i in live:
-            self._commit_spec(i, drafts[i], out_tok[i], int(out_len[i]),
-                              dt / len(live))
+        # every drafted slot waited for the whole verify pass
+        with TraceAnnotation("serve.emit"):
+            for i in live:
+                self._commit_spec(i, drafts[i], out_tok[i], int(out_len[i]),
+                                  now - t0)
 
     def _commit_spec(self, si, draft, out_row, n, dt):
         """Commit one slot's verify outcome: n = accepted drafts + 1
@@ -1874,6 +1940,8 @@ class Scheduler:
         (shed sweep + priority preemption at this chunk boundary), admit,
         then one speculative verify pass for slots with drafts (when spec
         is on) and/or one chunked decode for the rest."""
+        # only a chunk dispatched by this very step closes a host gap
+        synced, self._chunk_synced_t = self._chunk_synced_t, None
         self._expire_deadlines()
         if self.overload is not None:
             now = time.perf_counter()
@@ -1899,6 +1967,56 @@ class Scheduler:
         if not rest:
             return True
         t0 = time.perf_counter()
+        with TraceAnnotation("serve.decode_prep"):
+            args = self._decode_inputs(handled)
+
+        try:
+            self.cache, tok_buf, emit_buf, clens, rem, done = \
+                self.progs.decode_chunk(self.params, self.cache, *args)
+            dispatched = time.perf_counter()
+            # the ONE host sync of the chunk: pull tokens + masks + slot state
+            with TraceAnnotation("serve.decode_wait"):
+                tok_buf, emit_buf, clens_h, rem_h, done_h = jax.device_get(
+                    (tok_buf, emit_buf, clens, rem, done))
+        except Exception as e:
+            # failure isolation: a dead-lettered decode dispatch (retries
+            # exhausted / injected corruption) fails only the slots in this
+            # chunk — queued requests and the next step's admissions go on
+            self._fail_slots(rest, e)
+            return True
+        self._chunk_synced_t = time.perf_counter()
+        if synced is not None:
+            self._host_gap_s += dispatched - synced - self._sync_wait_s
+            self._host_gaps += 1
+        self._sync_wait_s = 0.0
+        self._breaker_note(True)
+        self._decode_syncs += 1
+        self._decode_chunks += 1
+        # every slot of the chunk waited for all of it
+        dt = self._chunk_synced_t - t0
+
+        with TraceAnnotation("serve.emit"):
+            emitted = 0
+            for i in rest:
+                slot = self.slots[i]
+                new = tok_buf[:, i][emit_buf[:, i]]
+                slot.generated.extend(int(t) for t in new)
+                if slot.drafter is not None and new.size:
+                    slot.drafter.extend([int(t) for t in new])
+                emitted += int(new.size)
+                slot.cache_len = int(clens_h[i])
+                slot.remaining = int(rem_h[i])
+                slot.request.decode_s += dt
+            self._decode_tokens += emitted
+            for i in rest:
+                stopped = self._apply_stop(self.slots[i])
+                if stopped or bool(done_h[i]):
+                    self._finalize(i)
+        return True
+
+    def _decode_inputs(self, handled) -> tuple:
+        """The decode chunk's per-slot inputs, built on the host from the
+        slots' state (and the device-cached per-slot constants)."""
         last = jnp.asarray([s.generated[-1] if s.request else 0
                             for s in self.slots], jnp.int32)
         clens = jnp.asarray([s.cache_len for s in self.slots], jnp.int32)
@@ -1935,44 +2053,8 @@ class Scheduler:
                            for s in self.slots]),
                 jnp.asarray([s.prompt_len for s in self.slots], jnp.int32))
         keys, plens = self._slot_consts
-        bt = self._decode_block_tables()
-
-        try:
-            self.cache, tok_buf, emit_buf, clens, rem, done = \
-                self.progs.decode_chunk(self.params, self.cache, last, clens,
-                                        rem, done, temps, top_ks, keys, plens,
-                                        bt)
-            # the ONE host sync of the chunk: pull tokens + masks + slot state
-            tok_buf, emit_buf, clens_h, rem_h, done_h = jax.device_get(
-                (tok_buf, emit_buf, clens, rem, done))
-        except Exception as e:
-            # failure isolation: a dead-lettered decode dispatch (retries
-            # exhausted / injected corruption) fails only the slots in this
-            # chunk — queued requests and the next step's admissions go on
-            self._fail_slots(rest, e)
-            return True
-        self._breaker_note(True)
-        self._decode_syncs += 1
-        self._decode_chunks += 1
-        dt = time.perf_counter() - t0
-
-        emitted = 0
-        for i in rest:
-            slot = self.slots[i]
-            new = tok_buf[:, i][emit_buf[:, i]]
-            slot.generated.extend(int(t) for t in new)
-            if slot.drafter is not None and new.size:
-                slot.drafter.extend([int(t) for t in new])
-            emitted += int(new.size)
-            slot.cache_len = int(clens_h[i])
-            slot.remaining = int(rem_h[i])
-            slot.request.decode_s += dt / max(len(rest), 1)
-        self._decode_tokens += emitted
-        for i in rest:
-            stopped = self._apply_stop(self.slots[i])
-            if stopped or bool(done_h[i]):
-                self._finalize(i)
-        return True
+        return (last, clens, rem, done, temps, top_ks, keys, plens,
+                self._decode_block_tables())
 
     def run_until_drained(self):
         while self.step() or self._queue:

@@ -52,6 +52,8 @@ import collections
 import enum
 from typing import Dict, Iterator, List, Optional, Union
 
+from jax.profiler import TraceAnnotation
+
 from repro.serving.faults import (CorruptionError, DeadLetterError,
                                   DeadlineExceeded, FaultError, FaultInjector,
                                   OverloadError, PumpStalledError,
@@ -360,6 +362,7 @@ class LLMServer:
                 "pump_stall_notices": self._pump.stall_notices,
                 "pump_compile_steps": self._pump.compile_steps,
                 "pump_longest_compile_s": self._pump.longest_compile_s,
+                "cmd_wait_hist": self._pump.cmd_wait.counts(),
             })
         return out
 
@@ -438,7 +441,8 @@ class LLMServer:
 
     def _step_impl(self) -> StepOutcome:
         progressed = self.engine.step()
-        self._deliver()
+        with TraceAnnotation("serve.deliver"):
+            self._deliver()
         if progressed:
             return StepOutcome.PROGRESSED
         # queue non-empty with no progress => every queued request is in

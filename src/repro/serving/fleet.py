@@ -314,6 +314,7 @@ class FleetServer:
             "journaled_sessions", "stream_chunks", "grouped_admissions",
             "engine_steps", "pump_steps", "pump_stall_notices",
             "pump_compile_steps", "host_gap_s", "host_gaps",
+            "decode_pages_grid", "decode_pages_live",
         ]
         with self._lock:
             out = {

@@ -59,6 +59,7 @@ import random
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.distributed import sharding
@@ -605,6 +606,10 @@ class Scheduler:
         self._host_gaps = 0
         self._chunk_synced_t: Optional[float] = None
         self._sync_wait_s = 0.0             # those syncs since then
+        # paged decode kernel work per executed decode step: the grid's
+        # pages (slots x table width) and those up to each row's cache_len
+        self._decode_pages_grid = 0
+        self._decode_pages_live = 0
 
         self.progs = EnginePrograms(
             self.model, self.cfg, self.engine_cfg, capacity=self.capacity,
@@ -1167,6 +1172,8 @@ class Scheduler:
                 "pages_free": self.kvpool.num_free,
                 "pages_peak_in_use": self.kvpool.peak_in_use,
                 "radix_evicted_pages": self.radix.evicted_pages,
+                "decode_pages_grid": self._decode_pages_grid,
+                "decode_pages_live": self._decode_pages_live,
             })
         if self.snapshots:
             out.update({
@@ -1969,6 +1976,7 @@ class Scheduler:
         t0 = time.perf_counter()
         with TraceAnnotation("serve.decode_prep"):
             args = self._decode_inputs(handled)
+            clens0 = [s.cache_len for s in self.slots]
 
         try:
             self.cache, tok_buf, emit_buf, clens, rem, done = \
@@ -2008,11 +2016,27 @@ class Scheduler:
                 slot.remaining = int(rem_h[i])
                 slot.request.decode_s += dt
             self._decode_tokens += emitted
+            if self.paged:
+                self._count_decode_pages(clens0, emit_buf)
             for i in rest:
                 stopped = self._apply_stop(self.slots[i])
                 if stopped or bool(done_h[i]):
                     self._finalize(i)
         return True
+
+    def _count_decode_pages(self, clens, emit_buf):
+        """The paged decode kernel's pages over one chunk's executed steps
+        (each emits for some row): the grid's, and the live ones, up to
+        the page of each row's cache_len. A row runs a step at its
+        dispatch length plus what it emitted before; a done or empty row
+        keeps its length."""
+        eb = np.asarray(emit_buf, np.int64)                 # [chunk, B]
+        ran = eb.any(axis=1)
+        pos = np.asarray(clens) + np.cumsum(eb, axis=0) - eb
+        nw = self._bt_width
+        self._decode_pages_grid += int(ran.sum()) * len(self.slots) * nw
+        self._decode_pages_live += int(np.minimum(
+            pos[ran] // self.engine_cfg.page_size + 1, nw).sum())
 
     def _decode_inputs(self, handled) -> tuple:
         """The decode chunk's per-slot inputs, built on the host from the

@@ -9,7 +9,7 @@ from repro.configs.registry import ARCHS
 from repro.kernels.paged_decode_attention import paged_decode_attention
 from repro.models import Model
 from repro.models import attention as attn
-from repro.serving.engine import EngineConfig, ServingEngine
+from repro.serving.engine import EngineConfig, SamplingParams, ServingEngine
 from repro.serving.kvpool import PagePool, block_table_array, supports_paged
 from repro.serving.radix import RadixTree
 
@@ -167,20 +167,57 @@ def test_radix_property_invariants(ops):
 # ---------------------------------------------------------------------------
 
 
-def test_paged_kernel_matches_dense_reference():
-    key = jax.random.PRNGKey(0)
-    B, P, ps, K, G, hd = 3, 11, 8, 2, 2, 16
-    k1, k2, k3 = jax.random.split(key, 3)
+# (block tables, cache_len) over a pool of 11 pages of 8, tables 4 wide:
+# a live row reads pages 0 .. cache_len // 8 of its table
+KERNEL_CASES = {
+    "mixed": ([[3, 1, 7, 10], [2, 5, 0, 0], [9, 8, 6, 4]], [25, 10, 31]),
+    # the new token opens a page (k * ps) / fills a page's last slot
+    "page_boundary": ([[3, 1, 7, 10], [2, 5, 0, 0], [9, 8, 6, 4]],
+                      [16, 15, 8]),
+    "full_table": ([[3, 1, 7, 10], [2, 5, 4, 6], [9, 8, 6, 4]],
+                   [31, 31, 31]),
+    "empty_row": ([[3, 1, 7, 10], [0, 0, 0, 0], [9, 8, 6, 4]], [25, 0, 23]),
+}
+
+
+def _kernel_inputs(B=3, P=11, ps=8, K=2, G=2, hd=16):
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
     kpool = jax.random.normal(k1, (P, ps, K, hd), jnp.float32)
     vpool = jax.random.normal(k2, (P, ps, K, hd), jnp.float32)
     q = jax.random.normal(k3, (B, 1, K * G, hd), jnp.float32)
-    bt = jnp.asarray([[3, 1, 7, 10], [2, 5, 0, 0], [9, 8, 6, 4]], jnp.int32)
-    clen = jnp.asarray([25, 10, 31], jnp.int32)
+    return q, kpool, vpool, G
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_paged_kernel_matches_dense_reference(case):
+    q, kpool, vpool, G = _kernel_inputs()
+    tables, lens = KERNEL_CASES[case]
+    bt = jnp.asarray(tables, jnp.int32)
+    clen = jnp.asarray(lens, jnp.int32)
     out = paged_decode_attention(q, kpool, vpool, bt, clen, q_per_kv=G)
     ref = attn.decode_attention(q, attn.paged_view(kpool, bt),
                                 attn.paged_view(vpool, bt), clen, q_per_kv=G)
     assert out.shape == ref.shape
     assert float(jnp.max(jnp.abs(out - ref))) < 1e-5
+
+
+def test_paged_kernel_never_reads_dead_pages():
+    """Table entries past each row's last live page point at NaN pages:
+    the output is finite and bit-identical to pointing them at the trash
+    page, so nothing of a dead page reaches the result (not even the
+    ``0 * NaN`` of a fully masked page)."""
+    q, kpool, vpool, G = _kernel_inputs(P=12)
+    clen = jnp.asarray([9, 0, 20], jnp.int32)          # 2, 1 and 3 live pages
+    trash = jnp.asarray([[3, 1, 0, 0], [2, 0, 0, 0], [9, 8, 6, 0]], jnp.int32)
+    dead = jnp.asarray([[3, 1, 4, 5], [2, 7, 10, 11], [9, 8, 6, 4]],
+                       jnp.int32)
+    nan_pages = jnp.asarray([4, 5, 7, 10, 11])
+    kpool = kpool.at[nan_pages].set(jnp.nan)
+    vpool = vpool.at[nan_pages].set(jnp.nan)
+    want = paged_decode_attention(q, kpool, vpool, trash, clen, q_per_kv=G)
+    got = paged_decode_attention(q, kpool, vpool, dead, clen, q_per_kv=G)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert bool(jnp.all(got == want))
 
 
 def test_paged_cache_update_routes_through_block_table():
@@ -280,3 +317,29 @@ def test_paged_sampling_determinism():
     a = e1.generate("sample me", max_new_tokens=8, temperature=1.1, top_k=12)
     b = e2.generate("sample me", max_new_tokens=8, temperature=1.1, top_k=12)
     assert a == b
+
+
+def test_decode_page_counters_count_grid_and_live_pages():
+    """Three slots, tables 64 / 8 = 8 pages wide, chunks of 4 steps. A
+    (prompt 16 tokens, 10 out) decodes at 16..24, B (23, 6 out) at 23..27
+    then sits out chunk 2 at 28; the third slot is empty (cache_len 0,
+    one page). Live pages per step: cache_len // 8 + 1."""
+    cfg = _cfg("qwen2.5-3b")
+    params = without_eos(Model(cfg).init(jax.random.PRNGKey(0)), cfg)
+    eng = ServingEngine(cfg, num_slots=3, capacity=64, params=params,
+                        engine_cfg=EngineConfig(cache_mode="paged",
+                                                page_size=8, decode_chunk=4))
+    assert eng.stats()["decode_pages_grid"] == 0
+    a = eng.enqueue("1" * 15, SamplingParams(max_new_tokens=10))  # BOS + 15
+    b = eng.enqueue("2" * 22, SamplingParams(max_new_tokens=6))
+    eng.run_until_drained()
+    assert (a.output_tokens, b.output_tokens) == (10, 6)
+    s = eng.stats()
+    assert s["decode_chunks"] == 3
+    # chunk 1: A 16-19 -> 3 x 4; B 23, 24-26 -> 3 + 4 x 3; empty 4 x 1
+    # chunk 2: A 20-23 -> 3 x 4; B 27, done at 28 -> 4 x 4; empty 4 x 1
+    # chunk 3 (one step, B's slot freed): A 24 -> 4; two empty slots 1 + 1
+    assert s["decode_pages_live"] == (12 + 15 + 4) + (12 + 16 + 4) + 6
+    assert s["decode_pages_grid"] == (4 + 4 + 1) * 3 * 8
+    dense = ServingEngine(cfg, num_slots=3, capacity=64, params=params)
+    assert "decode_pages_grid" not in dense.stats()
